@@ -39,7 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit
-from repro import compat
 from repro.configs.base import ShapeConfig, get_arch
 from repro.core.costmodel import estimate_serve_plan
 from repro.core.plan import single_stage_plan
@@ -100,7 +99,7 @@ def run_cell(arch_name: str, *, smoke: bool, batch: int, prompt_len: int,
     base_low = lower_plan(cfg, shape, base_plan, base_mesh)
     same_plan = plan.to_json() == base_plan.to_json()
 
-    with compat.set_mesh(base_mesh):
+    with jax.set_mesh(base_mesh):
         params, _ = model.init(jax.random.PRNGKey(0))
         prompts = jax.random.randint(
             jax.random.PRNGKey(1), (batch, prompt_len), 0,
@@ -110,7 +109,7 @@ def run_cell(arch_name: str, *, smoke: bool, batch: int, prompt_len: int,
     if same_plan:
         tuned_tps, tuned_toks = base_tps, base_toks
     else:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params, _ = model.init(jax.random.PRNGKey(0))
             prompts = jax.random.randint(
                 jax.random.PRNGKey(1), (batch, prompt_len), 0,
@@ -238,7 +237,7 @@ def run_trace(reps: int = 2) -> List[str]:
                              grad_accum=1, zero=0, ckpt_layers=0)
     mesh = make_host_mesh(1, 1)
     low = lower_plan(cfg, None, plan, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params, _ = model.init(jax.random.PRNGKey(0))
         buckets = _trace_requests(cfg)
         eng = ContinuousBatchingEngine(

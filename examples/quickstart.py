@@ -6,7 +6,6 @@ and run a few training steps of the reduced config locally.
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ShapeConfig, get_arch
 from repro.core.costmodel import estimate_plan
 from repro.core.plan import single_stage_plan
@@ -43,7 +42,7 @@ def main():
                              grad_accum=2, zero=tuned.zero,
                              ckpt_layers=min(tuned.ckpt_layers,
                                              rcfg.num_layers))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_train_step(model, plan, mesh, donate=False)
         state, _ = init_sharded_state(model, plan, mesh,
                                       jax.random.PRNGKey(0))

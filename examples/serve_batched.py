@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs.base import get_arch
 from repro.core.plan import single_stage_plan
 from repro.launch.mesh import make_host_mesh
@@ -47,7 +46,7 @@ def main():
     if args.continuous and max_len % args.page_size:
         raise SystemExit(f"--page-size {args.page_size} must divide "
                          f"prompt-len + gen = {max_len}")
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params, _ = model.init(jax.random.PRNGKey(0))
         rng = jax.random.PRNGKey(1)
         prompts = jax.random.randint(

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs.base import ArchConfig, register
 from repro.core.plan import single_stage_plan
 from repro.launch.mesh import make_host_mesh
@@ -50,7 +49,7 @@ def main():
     data = SyntheticLM(BatchSpec(global_batch=args.batch, seq_len=args.seq,
                                  vocab_size=M100.vocab_size), seed=7)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_train_step(model, plan, mesh)
         state, shardings = init_sharded_state(model, plan, mesh,
                                               jax.random.PRNGKey(0))

@@ -101,7 +101,6 @@ def measure_plan(cfg: ArchConfig, shape: ShapeConfig, plan: Plan, *,
     import jax
     import jax.numpy as jnp
 
-    from repro import compat
     from repro.launch.mesh import make_host_mesh
     from repro.lowering import lower_plan
     from repro.models.zoo import build_model
@@ -114,7 +113,7 @@ def measure_plan(cfg: ArchConfig, shape: ShapeConfig, plan: Plan, *,
     low = lower_plan(cfg, shape, plan, mesh)
     mem: Dict[str, Optional[float]] = {
         "modeled_peak_bytes": float(low.memory_report().peak_bytes)}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_train_step(model, plan, mesh, lowered=low)
         state, _shardings = init_sharded_state(
             model, plan, mesh, jax.random.PRNGKey(0), lowered=low)

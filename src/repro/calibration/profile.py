@@ -183,6 +183,10 @@ def profile_path(platform: str) -> Path:
     return profile_dir() / f"{platform}.json"
 
 
+# profiles shipped with the package, fitted on the platform's hardware
+SHIPPED_DIR = Path(__file__).resolve().parent / "profiles"
+
+
 def default_platform() -> str:
     """The jax backend name, or "cpu" in a jax-free container."""
     from repro import compat
@@ -197,11 +201,13 @@ def load_profile(platform: Optional[str] = None,
     """Resolve the active profile: explicit ``path`` >
     ``$REPRO_CALIBRATION_PROFILE`` > the per-platform file under
     ``$REPRO_CALIBRATION_DIR`` (default ``~/.cache/repro/calibration``) >
-    the frozen ``DEFAULT_PROFILE``."""
+    the platform's profile shipped in ``calibration/profiles/`` > the
+    frozen ``DEFAULT_PROFILE``."""
     env_path = os.environ.get("REPRO_CALIBRATION_PROFILE")
     if path is not None or env_path:
         return CalibrationProfile.load(path or env_path)
-    f = profile_path(platform or default_platform())
-    if f.exists():
-        return CalibrationProfile.load(f)
+    platform = platform or default_platform()
+    for f in (profile_path(platform), SHIPPED_DIR / f"{platform}.json"):
+        if f.exists():
+            return CalibrationProfile.load(f)
     return DEFAULT_PROFILE

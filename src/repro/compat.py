@@ -1,22 +1,10 @@
-"""jax API compatibility layer.
+"""jax helpers shared by the runtime and the analysis stack.
 
-The runtime code targets current jax (``jax.sharding.AxisType``,
-``jax.set_mesh``, top-level ``jax.shard_map`` with ``axis_names`` /
-``check_vma``).  The pinned CI / container environment may carry an older
-jax (0.4.x) where those spellings do not exist yet:
-
-  * ``Mesh`` takes no ``axis_types`` (every axis is implicitly Auto);
-  * ``AbstractMesh`` takes ``((name, size), ...)`` instead of
-    ``(shape, names)``;
-  * the ambient mesh is entered with the ``Mesh`` context manager rather
-    than ``jax.set_mesh``;
-  * ``shard_map`` lives in ``jax.experimental.shard_map`` and spells
-    partial-manual mode as ``auto=`` (the complement of ``axis_names``)
-    and replication checking as ``check_rep``.
-
-Every mesh/shard_map construction in the repo goes through this module so
-both API generations work.  Evaluate capabilities at call time (not import
-time) so test-time monkeypatching and upgrades behave predictably.
+The repo targets the installed jax (0.9): meshes carry explicit axis
+types, the ambient mesh is entered with ``jax.set_mesh`` and manual
+regions use ``jax.shard_map``.  ``make_mesh`` pins every axis to Auto
+(jax 0.9 defaults ``jax.make_mesh`` to Explicit axes, which the
+GSPMD-annotated step builders do not use).
 
 The module also gates jax *availability* for the analysis stack: the
 tuner / cost-model path is pure numpy and must keep working in numpy-only
@@ -27,7 +15,6 @@ importing jax themselves.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 from typing import Sequence, Tuple
 
@@ -55,7 +42,7 @@ def require_jax() -> Tuple["jax", "jax.numpy"]:
 
 def jax_x64_enabled() -> bool:
     """Whether jax currently produces 64-bit floats (honors both the
-    global ``jax_enable_x64`` flag and the thread-local ``enable_x64``
+    global ``jax_enable_x64`` flag and the scoped ``jax.enable_x64``
     context).  The tape backends' bitwise-equivalence-to-numpy guarantee
     holds only when this is True; ``backend="auto"`` refuses jax
     otherwise."""
@@ -68,125 +55,36 @@ def jax_x64_enabled() -> bool:
 
 def enable_x64():
     """Context manager forcing 64-bit jax types inside the block (the
-    backend equivalence suite runs under it).  Uses
-    ``jax.experimental.enable_x64`` where it exists; falls back to
-    flipping the config flag (not thread-safe, but only reachable on jax
-    versions without the scoped context)."""
+    backend equivalence suite runs under it)."""
     if jax is None:
         raise ImportError("jax is unavailable; cannot enable x64")
-    ctx = getattr(getattr(jax, "experimental", None), "enable_x64", None)
-    if ctx is not None:
-        return ctx()
-
-    @contextlib.contextmanager
-    def _flag():
-        old = bool(jax.config.jax_enable_x64)
-        jax.config.update("jax_enable_x64", True)
-        try:
-            yield
-        finally:
-            jax.config.update("jax_enable_x64", old)
-    return _flag()
-
-
-def axis_type_auto():
-    """``jax.sharding.AxisType.Auto`` where it exists, else None."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return getattr(at, "Auto", None)
+    return jax.enable_x64(True)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with every axis Auto, across the API drift."""
-    auto = axis_type_auto()
-    if auto is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis Auto."""
+    auto = jax.sharding.AxisType.Auto
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(auto,) * len(axes))
 
 
-def abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Device-less mesh carrying only (name, size) metadata."""
-    AM = jax.sharding.AbstractMesh
-    try:
-        return AM(tuple(shape), tuple(axes))
-    except TypeError:   # 0.4.x signature: ((name, size), ...)
-        return AM(tuple(zip(axes, shape)))
-
-
-def set_mesh(mesh):
-    """Context manager making ``mesh`` ambient (``jax.set_mesh`` on new
-    jax; the ``Mesh`` object is itself the context manager on old jax)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs,
-              axis_names: frozenset, check_vma: bool = True):
-    """Partial-manual shard_map: ``axis_names`` are manual, the rest of the
-    mesh axes stay auto (XLA SPMD keeps handling them)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    # old check_rep uses a different (per-primitive replication-rule)
-    # mechanism that rejects with_sharding_constraint inside the region;
-    # replication of outputs is established explicitly by the callers
-    # (psum over the manual axis), so it is safe to disable.
-    return _sm(f, mesh, in_specs, out_specs, check_rep=False, auto=auto)
+HOST_MEMORY_KIND = "pinned_host"
 
 
 @functools.lru_cache(maxsize=1)
-def host_memory_kind():
-    """Memory kind for host-offloaded state ("pinned_host"), or None when
-    the backend has no separate host memory space (jax 0.4.x CPU exposes
-    only "unpinned_host", which is also the default device memory there).
-    None means offload ratios degrade gracefully to resident placement —
-    values and update math are unchanged, only the placement differs.
-    Cached: callers probe it per pytree leaf, and a backend's memory
-    spaces don't change within a process."""
-    try:
-        kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
-    except Exception:       # pragma: no cover - exotic backends
-        return None
-    return "pinned_host" if "pinned_host" in kinds else None
+def host_memory_kind() -> str:
+    """Memory kind for host-offloaded state: ``"pinned_host"``.
 
-
-def jax_version() -> Tuple[int, ...]:
-    """jax's version as an int tuple ((0,) when jax is absent or the
-    version string is exotic).  Capability gates that guard against
-    *bundled-XLA* behavior — which no Python-API probe can see — compare
-    against this."""
-    if jax is None:
-        return (0,)
-    out = []
-    for part in str(getattr(jax, "__version__", "0")).split("."):
-        digits = ""
-        for c in part:  # digits *prefix*: "37rc1" is 37, not 371
-            if not c.isdigit():
-                break
-            digits += c
-        if not digits:
-            break
-        out.append(int(digits))
-    return tuple(out) or (0,)
-
-
-def supports_pipeline_stage_mapping() -> bool:
-    """Whether this jax can run the pipeline executor's partial-manual
-    shard_map (scan + ppermute over a manual 'stage' axis with auto
-    data/model axes).  On jax 0.4.x — including the container's pinned
-    0.4.37 — the bundled XLA SPMD partitioner hard CHECK-fails on that
-    pattern (hlo_sharding_util IsManualSubgroup), so the pipeline train
-    step is gated to jax >= 0.5; single-stage SPMD, tuning, and all
-    analysis paths are unaffected.
-
-    The version floor is checked EXPLICITLY, not inferred from
-    ``hasattr(jax, "shard_map")``: the crash lives in the bundled XLA,
-    not the Python API, so a 0.4.x that aliased ``shard_map`` to the
-    top level (or a test monkeypatch) must still be rejected.  The API
-    probe stays as the second conjunct because the executor also needs
-    the new ``axis_names``/``check_vma`` spelling's semantics."""
-    return jax_version() >= (0, 5) and hasattr(jax, "shard_map")
+    Every backend the repo runs on (TPU, and the CPU of jax 0.9) exposes
+    it; a backend without it raises instead of quietly keeping offloaded
+    state resident, so a plan's WO/OO/AO ratios always mean what the
+    tuner priced.  Cached: callers probe it per pytree leaf, and a
+    backend's memory spaces don't change within a process."""
+    dev = jax.devices()[0]
+    kinds = {m.kind for m in dev.addressable_memories()}
+    if HOST_MEMORY_KIND not in kinds:
+        raise RuntimeError(
+            f"{dev.platform} device {dev.device_kind!r} has no pinned_host "
+            f"memory (memory kinds: {sorted(kinds)}); host offload "
+            f"(wo/oo/ao > 0) cannot be placed")
+    return HOST_MEMORY_KIND

@@ -70,6 +70,11 @@ class CostParams:
     # Predictor and memory_report both read THIS field, so the
     # predicted-vs-lowered cross-check is independent of its value.
     runtime_reserved: float = 0.75 * 2**30
+    # What the target's compiler holds for the step's temporaries beyond
+    # their analytic bytes (heap fragmentation, buffers the terms do not
+    # see), as an extra fraction of act + transient + logits.  0 here;
+    # a platform's CalibrationProfile carries its fitted value.
+    act_overhead: float = 0.0
     # serving (docs/serving.md): decode-step working-set envelope and the
     # decode roofline's MXU efficiency (GEMV-shaped matmuls run far below
     # the big-matmul peak)
@@ -315,9 +320,16 @@ class StageCostModel:
         logits = (2.0 * b * min(512, seq) * st.vocab * 4.0 / tp
                   if self.has_head else wrap(0.0))
 
-        self.mem_fwd: Expr = states + acts + trans + logits + cp.runtime_reserved
-        self.mem_bwd: Expr = states + acts + trans + logits \
-            + act_full_l + cp.runtime_reserved  # recompute scratch in bwd
+        fwd = states + acts + trans + logits
+        bwd = fwd + act_full_l                  # recompute scratch in bwd
+        over = wrap(0.0)
+        if cp.act_overhead:
+            temps = acts + trans + logits
+            over = cp.act_overhead * (temps + act_full_l)
+            fwd = fwd + cp.act_overhead * temps
+            bwd = bwd + over
+        self.mem_fwd: Expr = fwd + cp.runtime_reserved
+        self.mem_bwd: Expr = bwd + cp.runtime_reserved
         # per-term peak-memory breakdown (bwd side == the peak, since bwd
         # only adds the recompute scratch): evaluated by estimate_plan /
         # memory_consistency so predicted-vs-lowered disagreement is
@@ -326,6 +338,7 @@ class StageCostModel:
             "state": states, "act": acts,
             "transient": trans + act_full_l, "logits": wrap(logits),
             "host_state": lay["host"], "host_act": host_acts,
+            "overhead": over,
         }
 
         # ---- kernel VMEM working set (grid legality, not HBM peak) ----------
@@ -871,7 +884,9 @@ def estimate_plan(cfg: ArchConfig, shape: ShapeConfig, plan, *,
         "mem_peak_max": max(mems), "mem_per_stage": mems,
         "mem_terms_per_stage": terms,
         "t_stable_per_stage": ts, "d_delta_per_stage": ds,
-        "fits": max(mems) <= hw.hbm_bytes * cp.mem_headroom,
+        "fits": max(mems) <= hw.hbm_bytes * (
+            profile.cost_params(cp) if profile is not None
+            else cp).mem_headroom,
     }
 
 

@@ -24,3 +24,17 @@ class HardwareSpec:
 
 
 V5E = HardwareSpec()
+
+# the ``device_kind`` strings JAX reports for the chips specified above
+_BY_DEVICE_KIND = {"TPU v5 lite": V5E, "TPU v5e": V5E}
+
+
+def spec_for_device_kind(device_kind: str) -> HardwareSpec:
+    """The spec of the chip JAX reports as ``device_kind``.  A chip this
+    module has no spec for is an error, never a silent V5E."""
+    try:
+        return _BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware spec for device_kind {device_kind!r} "
+            f"(known: {sorted(_BY_DEVICE_KIND)})") from None

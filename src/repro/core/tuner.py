@@ -513,8 +513,9 @@ class MistTuner:
 
 
 def tune(arch: ArchConfig, shape: ShapeConfig, n_devices: int,
-         space: str = "mist", **kw) -> TuneReport:
+         space: str = "mist", *, hw: HardwareSpec = V5E,
+         **kw) -> TuneReport:
     spec = TuneSpec(arch=arch, seq_len=shape.seq_len,
                     global_batch=shape.global_batch, n_devices=n_devices,
                     space=space, **kw)
-    return MistTuner(spec).tune()
+    return MistTuner(spec, hw=hw).tune()

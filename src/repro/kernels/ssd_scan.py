@@ -17,7 +17,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hstate_ref, *,
+def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, y_ref, hstate_ref, *,
                 chunk: int):
     ci = pl.program_id(2)
 
@@ -26,37 +26,49 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hstate_ref, *,
         hstate_ref[...] = jnp.zeros_like(hstate_ref)
 
     x = x_ref[0, 0, 0].astype(jnp.float32)     # (Q, P)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)   # (Q,)
-    a = a_ref[0]                               # scalar
+    dt_row = dt_ref[0, 0, 0]                   # (1, Q) f32
+    da_row = da_ref[0, 0, 0]                   # (1, Q) f32: dt * a
     bb = b_ref[0, 0, 0].astype(jnp.float32)    # (Q, N)
     cc = c_ref[0, 0, 0].astype(jnp.float32)    # (Q, N)
 
-    da = dt * a                                # (Q,)
-    cum = jnp.cumsum(da)                       # (Q,)
-    # intra-chunk
-    rel = cum[:, None] - cum[None, :]          # (Qt, Qs)
+    # dt/da arrive as lane rows (the TPU block tiling refuses the former
+    # (1, 1, 1, Q) block); their column forms and prefix sums are masked
+    # 2-D reductions, so every value stays in a 2-D (sublane, lane) layout
     q = x.shape[0]
-    tri = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    t_ix = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    s_ix = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    tri = t_ix >= s_ix
+
+    def col(row):                              # (1, Q) -> (Q, 1)
+        return jnp.sum(jnp.where(t_ix == s_ix, row, 0.0), axis=1,
+                       keepdims=True)
+
+    dt_col, da_col = col(dt_row), col(da_row)
+    cum_col = jnp.sum(jnp.where(tri, da_row, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(t_ix <= s_ix, da_col, 0.0), axis=0,
+                      keepdims=True)
+    total = jnp.sum(da_row, axis=1, keepdims=True)          # (1, 1)
+    # intra-chunk
+    rel = cum_col - cum_row                    # (Qt, Qs)
     # mask BEFORE exp: above the diagonal rel > 0 can overflow (and the
     # where-after-exp pattern NaNs the backward via inf*0)
     lmat = jnp.exp(jnp.where(tri, rel, -jnp.inf))
     scores = jax.lax.dot_general(cc, bb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    scores = scores * lmat * dt[None, :]
+    scores = scores * lmat * dt_row
     y = jax.lax.dot_general(scores, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     # inter-chunk from carried state: y += (C * exp(cum)) @ h
     h = hstate_ref[...]                        # (N, P)
-    cdec = cc * jnp.exp(cum)[:, None]
+    cdec = cc * jnp.exp(cum_col)
     y = y + jax.lax.dot_general(cdec, h, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     # state update: h' = exp(sum da) * h + sum_s exp(cum_Q - cum_s) dt_s B_s x_s^T
-    w = jnp.exp(cum[-1] - cum) * dt            # (Q,)
-    new_state = jax.lax.dot_general(bb * w[:, None], x,
+    w = jnp.exp(total - cum_col) * dt_col      # (Q, 1)
+    new_state = jax.lax.dot_general(bb * w, x,
                                     (((0,), (0,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-    hstate_ref[...] = h * jnp.exp(cum[-1]) + new_state
+    hstate_ref[...] = h * jnp.exp(total) + new_state
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
 
@@ -73,20 +85,22 @@ def ssd_scan_pallas(xh: jax.Array, dt: jax.Array, a: jax.Array,
     q = min(chunk, s)
     assert s % q == 0
     nc = s // q
-    # layout: (B, H, C, Q, .)
+    # layout: (B, H, C, Q, .); dt and dt*a as (B, H, C, 1, Q) lane rows
     xt = xh.transpose(0, 2, 1, 3).reshape(bsz, h, nc, q, p)
-    dtt = dt.transpose(0, 2, 1).reshape(bsz, h, nc, q).astype(jnp.float32)
+    dtt = dt.transpose(0, 2, 1).reshape(bsz, h, nc, 1, q).astype(jnp.float32)
+    dat = dtt * a.astype(jnp.float32)[None, :, None, None, None]
     bt = bb.transpose(0, 2, 1, 3).reshape(bsz, h, nc, q, n)
     ct = cc.transpose(0, 2, 1, 3).reshape(bsz, h, nc, q, n)
 
     grid = (bsz, h, nc)
+    row = pl.BlockSpec((1, 1, 1, 1, q), lambda b, hh, c: (b, hh, c, 0, 0))
     out = pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=q),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, q, p), lambda b, hh, c: (b, hh, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, q), lambda b, hh, c: (b, hh, c, 0)),
-            pl.BlockSpec((1,), lambda b, hh, c: (hh,)),
+            row,
+            row,
             pl.BlockSpec((1, 1, 1, q, n), lambda b, hh, c: (b, hh, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, q, n), lambda b, hh, c: (b, hh, c, 0, 0)),
         ],
@@ -95,5 +109,5 @@ def ssd_scan_pallas(xh: jax.Array, dt: jax.Array, a: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bsz, h, nc, q, p), xh.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(xt, dtt, a.astype(jnp.float32), bt, ct)
+    )(xt, dtt, dat, bt, ct)
     return out.reshape(bsz, h, s, p).transpose(0, 2, 1, 3)

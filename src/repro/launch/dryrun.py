@@ -195,7 +195,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
     params_sds, axes_table = low.params_sds, low.axes_table
 
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step = make_train_step(model, plan, mesh, lowered=low)
             state_abs = OPT.init_state(params_sds, axes_table, plan.stages[0])
@@ -227,8 +227,6 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     hlo_text = compiled.as_text()
     stats = analyze(hlo_text)
     chips = mesh.devices.size

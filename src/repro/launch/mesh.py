@@ -1,6 +1,6 @@
 """Production mesh construction (defined as functions so importing this
 module never touches jax device state).  Meshes are built through
-`repro.compat` so both old (0.4.x) and current jax APIs work."""
+`repro.compat.make_mesh`, which pins every axis to Auto."""
 from __future__ import annotations
 
 from typing import Optional, Tuple

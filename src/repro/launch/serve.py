@@ -16,7 +16,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-from repro import compat
 import numpy as np
 
 
@@ -75,15 +74,15 @@ def tuned_serve_plan(cfg, *, batch: int, max_len: int, n_devices: int,
 
 def run_continuous(model, params, prompts, gens, mesh, plan, *,
                    slots: int, page_size: int, max_len: int, lowered=None):
-    """Serve one request per prompt row (per-request output budgets
-    ``gens``) through the continuous-batching engine; returns
-    ({rid: tokens}, engine)."""
+    """Serve one request per prompt (a (1, n_i) token array each; lengths
+    may differ) with per-request output budgets ``gens`` through the
+    continuous-batching engine; returns ({rid: tokens}, engine)."""
     from repro.serving import ContinuousBatchingEngine
     eng = ContinuousBatchingEngine(model, params, plan, mesh, slots=slots,
                                    max_len=max_len, page_size=page_size,
                                    lowered=lowered)
-    for i in range(prompts.shape[0]):
-        eng.submit({"tokens": prompts[i:i + 1]}, gens[i], rid=i)
+    for i, p in enumerate(prompts):
+        eng.submit({"tokens": p}, gens[i], rid=i)
     return eng.run(), eng
 
 
@@ -109,6 +108,9 @@ def main():
     args = ap.parse_args()
 
     from repro.configs.base import get_arch
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.core.plan import single_stage_plan
     from repro.launch.mesh import make_host_mesh
     from repro.models.zoo import build_model
@@ -153,7 +155,7 @@ def main():
         assert est["mem_decode"] == rep.peak_bytes, \
             (est["mem_decode"], rep.peak_bytes)
         print("# predicted serve memory == memory_report(): bitwise OK")
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params, _ = model.init(jax.random.PRNGKey(0))
         prompts = jax.random.randint(jax.random.PRNGKey(1),
                                      (args.batch, args.prompt_len), 0,
@@ -166,9 +168,10 @@ def main():
                     for i in range(args.batch)]
             ps = plan.page_size or args.page_size
             t0 = time.time()
-            res, eng = run_continuous(model, params, prompts, gens, mesh,
-                                      plan, slots=args.slots, page_size=ps,
-                                      max_len=max_len, lowered=low)
+            res, eng = run_continuous(model, params, prompts[:, None], gens,
+                                      mesh, plan, slots=args.slots,
+                                      page_size=ps, max_len=max_len,
+                                      lowered=low)
             dt = time.time() - t0
             ref = generate(model, params, prompts, args.gen, mesh, plan,
                            lowered=low, max_len=max_len)

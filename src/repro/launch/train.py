@@ -18,7 +18,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-from repro import compat
 import numpy as np
 
 
@@ -47,12 +46,16 @@ def main():
     ap.add_argument("--calibration-out", default=None, metavar="PATH|auto",
                     help="with --calibrate: persist the fitted profile "
                          "(auto = the platform's default cache location)")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=str(
+        pathlib.Path(__file__).resolve().parents[3] / "results" / "ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=10)
     args = ap.parse_args()
 
     from repro.configs.base import ShapeConfig, get_arch
     from repro.core.plan import Plan
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     shape = ShapeConfig("cli", args.seq, args.global_batch, "train")
@@ -68,13 +71,17 @@ def main():
         return 0 if (report["mean_err_fitted"]
                      <= report["mean_err_uncalibrated"] + 1e-12) else 1
 
+    # the platform's fitted constants (docs/calibration.md); on a TPU v5e
+    # the shipped profile prices what the chip's compiler holds in HBM
+    from repro.calibration import load_profile
+    profile = load_profile()
     plan = None
     if args.plan_json:
         plan = Plan.from_json(pathlib.Path(args.plan_json).read_text())
     elif args.tune:
         from repro.core.tuner import tune
         rep = tune(cfg, shape, args.devices, space=args.space,
-                   memo_dir=args.memo_dir)
+                   memo_dir=args.memo_dir, profile=profile)
         if rep.plan is None:
             print("INFEASIBLE for this device count / batch")
             return 1
@@ -97,7 +104,7 @@ def main():
             st0 = plan.stages[0]
             sshape = ShapeConfig("cli", args.seq, args.global_batch,
                                  "decode")
-            mesh = compat.abstract_mesh((st0.dp, st0.tp),
+            mesh = jax.sharding.AbstractMesh((st0.dp, st0.tp),
                                         ("data", "model"))
             rep = lower_plan(cfg, sshape, plan, mesh).memory_report()
             est = estimate_serve_plan(cfg, sshape, plan)
@@ -107,7 +114,7 @@ def main():
                   f"(bitwise={est['mem_decode'] == rep.peak_bytes})")
         else:
             from repro.lowering import memory_consistency
-            mc = memory_consistency(cfg, shape, plan)
+            mc = memory_consistency(cfg, shape, plan, profile=profile)
             print(f"# memory: predicted "
                   f"{mc['predicted_bytes'] / 2**30:.2f} GiB "
                   f"lowered {mc['lowered_bytes'] / 2**30:.2f} GiB "
@@ -147,7 +154,7 @@ def main():
     rep = low.memory_report()
     print(f"# smoke plan lowered: peak {rep.peak_bytes / 2**30:.2f} GiB "
           f"per device (fits={rep.fits})")
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_train_step(model, plan, mesh, lowered=low)
         state, shardings = init_sharded_state(model, plan, mesh,
                                               jax.random.PRNGKey(0),

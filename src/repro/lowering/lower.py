@@ -8,12 +8,11 @@ Lowering is split in two strictly separated halves:
   and attention implementation selection), and — for S > 1 — the pipeline
   stage-block tables (stacked 'stage'-dim specs + the shard_map manual
   specs).  This half never touches devices, so it runs identically on
-  concrete meshes and on :func:`repro.compat.abstract_mesh` shells (the
+  concrete meshes and on ``jax.sharding.AbstractMesh`` shells (the
   dryrun / analysis path).
 
 * **LoweredPlan methods** materialize that metadata into NamedShardings
-  (including ``pinned_host`` memory kinds for offloaded slices, with the
-  same graceful degradation as before via ``repro.compat``) on demand —
+  (including ``pinned_host`` memory kinds for offloaded slices) on demand —
   only execution paths pay for it, and only they need real devices.
 
 The spec *functions* (param_spec / grad_spec / opt_spec / cache_specs /
@@ -144,22 +143,20 @@ class LoweredPlan:
     def state_shardings(self, i: int = 0) -> Dict[str, Any]:
         """NamedShardings mirroring the optimizer-state pytree: params by
         param_specs, master/mu/nu by opt_specs, WO/OO-split leaves as
-        {"host", "dev"} pairs with the host part on ``pinned_host`` (or
-        resident where the backend has no host memory space)."""
+        {"host", "dev"} pairs with the host part on ``pinned_host``."""
         st = self.stages[i]
         return self._opt_tree(st.param_specs, st.opt_specs,
                               st.master_split, st.opt_split)
 
     def _opt_tree(self, pspecs, ospecs, master_split, opt_split):
         from jax.sharding import NamedSharding
-        hk = compat.host_memory_kind()
 
         def entry(split):
             out = {}
             for n, spec in ospecs.items():
                 if split.get(n, 0):
-                    host = (NamedSharding(self.mesh, spec, memory_kind=hk)
-                            if hk else NamedSharding(self.mesh, spec))
+                    host = NamedSharding(self.mesh, spec,
+                                         memory_kind=compat.host_memory_kind())
                     out[n] = {"host": host,
                               "dev": NamedSharding(self.mesh, spec)}
                 else:
@@ -277,7 +274,7 @@ def lower_plan(cfg: ArchConfig, shape: Optional[ShapeConfig], plan: Plan,
     ``shape`` is the workload the plan was tuned for; it is carried for
     ``memory_report`` and may be None for pure-execution callers that
     never ask for one.  ``mesh`` may be a concrete mesh (execution) or an
-    ``repro.compat.abstract_mesh`` shell (analysis).  Raises ValueError
+    ``jax.sharding.AbstractMesh`` shell (analysis).  Raises ValueError
     when the mesh axis sizes disagree with the plan's parallel degrees
     (``check_plan_mesh``).
     """

@@ -79,6 +79,7 @@ class StageMemory:
     cache_bytes: float = 0.0         # KV/state caches (serving)
     transient_bytes: float = 0.0     # working set + recompute scratch
     logits_bytes: float = 0.0
+    overhead_bytes: float = 0.0      # compiler's share beyond act+trans+logits
     reserved_bytes: float = 0.0      # XLA runtime + fragmentation
 
     @property
@@ -90,7 +91,7 @@ class StageMemory:
     def device_bytes(self) -> float:
         return (self.state_bytes + self.act_bytes + self.cache_bytes
                 + self.transient_bytes + self.logits_bytes
-                + self.reserved_bytes)
+                + self.overhead_bytes + self.reserved_bytes)
 
 
 @dataclass(frozen=True)
@@ -239,6 +240,7 @@ def memory_report(lowered: "LoweredPlan", *, hw: HardwareSpec = V5E,
             opt_bytes=state["opt"], host_state_bytes=state["host"],
             act_bytes=act, host_act_bytes=act_host,
             transient_bytes=trans, logits_bytes=logits,
+            overhead_bytes=cp.act_overhead * (act + trans + logits),
             reserved_bytes=cp.runtime_reserved))
     return MemoryReport(kind="train", stages=tuple(stages),
                         budget_bytes=budget)
@@ -314,7 +316,8 @@ def _serve_report(lowered: "LoweredPlan", stt, shape: ShapeConfig,
 
 
 def memory_consistency(cfg: ArchConfig, shape: ShapeConfig, plan, *,
-                       hw: HardwareSpec = V5E) -> Dict[str, Any]:
+                       hw: HardwareSpec = V5E,
+                       profile=None) -> Dict[str, Any]:
     """Predicted (symbolic estimate_plan) vs lowered (memory_report)
     per-device peak bytes for one concrete plan, on an abstract mesh
     shaped exactly like the plan.  This is the tuner->runtime consistency
@@ -324,19 +327,21 @@ def memory_consistency(cfg: ArchConfig, shape: ShapeConfig, plan, *,
     ``terms`` breaks the gap down per memory term at the lowered peak
     stage.  Per-term rel errors are normalized by the predicted TOTAL
     (how much of the budget that term's disagreement is worth), so tiny
-    terms cannot blow the ratio up."""
-    from repro import compat
+    terms cannot blow the ratio up.  ``profile`` (a CalibrationProfile)
+    layers its fitted constants over both sides."""
+    from jax.sharding import AbstractMesh
     from repro.core.costmodel import estimate_plan
     from repro.lowering.lower import lower_plan
 
-    est = estimate_plan(cfg, shape, plan, hw=hw)
+    est = estimate_plan(cfg, shape, plan, hw=hw, profile=profile)
+    cp = profile.cost_params() if profile is not None else None
     st0 = plan.stages[0]
     if plan.num_stages > 1:
-        mesh = compat.abstract_mesh(
+        mesh = AbstractMesh(
             (plan.num_stages, st0.dp, st0.tp), ("stage", "data", "model"))
     else:
-        mesh = compat.abstract_mesh((st0.dp, st0.tp), ("data", "model"))
-    rep = lower_plan(cfg, shape, plan, mesh).memory_report(hw=hw)
+        mesh = AbstractMesh((st0.dp, st0.tp), ("data", "model"))
+    rep = lower_plan(cfg, shape, plan, mesh).memory_report(hw=hw, cp=cp)
     predicted = float(est["mem_peak_max"])
     lowered_b = float(rep.peak_bytes)
     rel = abs(lowered_b - predicted) / max(predicted, 1.0)
@@ -348,6 +353,7 @@ def memory_consistency(cfg: ArchConfig, shape: ShapeConfig, plan, *,
     lowered_terms = {"state": ps.state_bytes, "act": ps.act_bytes,
                      "transient": ps.transient_bytes,
                      "logits": ps.logits_bytes,
+                     "overhead": ps.overhead_bytes,
                      "host_state": ps.host_state_bytes,
                      "host_act": ps.host_act_bytes}
     terms = {k: {"predicted": float(pt[k]), "lowered": float(v),

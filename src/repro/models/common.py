@@ -215,16 +215,10 @@ def _remat_wrap(body: Callable, policy: str, offload: bool) -> Callable:
         return body
     if offload:
         from repro import compat
-        if compat.host_memory_kind() is None:
-            # no separate host memory space on this backend: keep the same
-            # saved/recomputed segmentation, resident instead of offloaded
-            pol = jax.checkpoint_policies.save_only_these_names(
-                "resid", "layer_in")
-        else:
-            pol = jax.checkpoint_policies.save_and_offload_only_these_names(
-                names_which_can_be_saved=[],
-                names_which_can_be_offloaded=["resid", "layer_in"],
-                offload_src="device", offload_dst=compat.host_memory_kind())
+        pol = jax.checkpoint_policies.save_and_offload_only_these_names(
+            names_which_can_be_saved=[],
+            names_which_can_be_offloaded=["resid", "layer_in"],
+            offload_src="device", offload_dst=compat.host_memory_kind())
     elif policy == "dots":
         pol = jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
     elif policy == "full":

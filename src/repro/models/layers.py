@@ -141,6 +141,34 @@ def _sdpa(q, k, v, cfg: ArchConfig, mask_kind: str, q_pos0=None,
     return jnp.einsum("bkgqs,bskh->bqkgh", w.astype(v.dtype), v)
 
 
+def _per_device(fn, batch: int, kv_heads: int):
+    """Run an attention kernel on each device's block of (B, S, heads, hd)
+    operands.  GSPMD cannot partition a Pallas (Mosaic) kernel, so the
+    call is shard_mapped over the axes the batch ('dp') and heads ('tp')
+    are sharded on; an axis that does not divide its dim stays
+    replicated."""
+    from jax.sharding import PartitionSpec as P
+    from repro.models.common import current_rules
+    from repro.parallel.sharding import axis_size
+    rules = current_rules()
+    if rules is None or rules.mesh is None:
+        return fn
+    dp, tp = rules.mapping.get("dp"), rules.mapping.get("tp")
+    if dp is not None and batch % axis_size(rules.mesh, dp):
+        dp = None
+    if tp is not None and kv_heads % axis_size(rules.mesh, tp):
+        tp = None
+    spec = P(dp, None, tp, None)
+    manual = {a for ax in (dp, tp) if ax is not None
+              for a in ((ax,) if isinstance(ax, str) else ax)}
+    if not manual:
+        return fn
+    mesh = jax.sharding.get_abstract_mesh()
+    return jax.shard_map(fn, mesh=rules.mesh if mesh.empty else mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         axis_names=manual, check_vma=False)
+
+
 def attention(p: Params, x: jax.Array, cfg: ArchConfig, exec_cfg: ExecConfig,
               *, positions: Optional[jax.Array] = None, mask_kind="causal",
               cache: Optional[Dict] = None, kv_x: Optional[jax.Array] = None,
@@ -188,11 +216,16 @@ def attention(p: Params, x: jax.Array, cfg: ArchConfig, exec_cfg: ExecConfig,
         k = shard_act(k, ("dp", None, "tp", None))
         if exec_cfg.attn_impl != "naive":
             from repro.kernels import ops as kops
-            ctx = kops.attention(q, k, v, causal=(mask_kind == "causal"),
-                                 impl=exec_cfg.attn_impl,
-                                 q_block=exec_cfg.attn_q_block,
-                                 kv_block=exec_cfg.attn_kv_block)
-            ctx = ctx.reshape(b_, s, kv, g, hd)
+
+            def attn(q_, k_, v_):
+                return kops.attention(q_, k_, v_,
+                                      causal=(mask_kind == "causal"),
+                                      impl=exec_cfg.attn_impl,
+                                      q_block=exec_cfg.attn_q_block,
+                                      kv_block=exec_cfg.attn_kv_block)
+            if exec_cfg.attn_impl == "pallas":
+                attn = _per_device(attn, q.shape[0], kv)
+            ctx = attn(q, k, v).reshape(b_, s, kv, g, hd)
         else:
             qg = q.reshape(b_, s, kv, g, hd)
             ctx = _sdpa(qg, k, v, cfg, mask_kind)

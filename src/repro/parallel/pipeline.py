@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.core.plan import Plan
 from repro.lowering import LoweredPlan, lower_plan
@@ -105,14 +104,6 @@ def make_pipeline_loss(model: Model, plan: Plan, mesh: Mesh,
     partial-manual shard_map over the 'stage' axis."""
     cfg = model.cfg
     assert supports_pipeline(cfg), f"pipeline unsupported for {cfg.family}"
-    if not compat.supports_pipeline_stage_mapping():
-        # fail fast with a clear error: on jax 0.4.x the bundled XLA SPMD
-        # partitioner aborts the whole process (CHECK failure) on
-        # partial-manual scan+ppermute, so don't even build the program.
-        raise NotImplementedError(
-            "pipeline stage mapping needs partial-manual shard_map "
-            "(jax.shard_map); this jax is too old — single-stage SPMD and "
-            "all tuning/analysis paths remain available")
     low = lowered or lower_plan(cfg, None, plan, mesh)
     S = plan.num_stages
     G = plan.grad_accum
@@ -190,7 +181,7 @@ def make_pipeline_loss(model: Model, plan: Plan, mesh: Mesh,
     # check_vma=False: inner scans (chunked xent, layer scan) carry
     # stage-varying values from unvarying seeds; the loss output is made
     # replicated explicitly via the psum over 'stage'.
-    smapped = compat.shard_map(pipelined, mesh=mesh, in_specs=in_specs,
+    smapped = jax.shard_map(pipelined, mesh=mesh, in_specs=in_specs,
                                out_specs=P(), axis_names=manual,
                                check_vma=False)
 

@@ -14,7 +14,12 @@ page-in copies the exact prefill rows, and the paged decode step
 state before every token — so each request's greedy tokens equal
 ``launch.serve.generate()`` run at the same ``max_len``, token for
 token (asserted in tests/test_serving_engine.py and the --trace
-benchmark headline).
+benchmark headline).  On a TPU v5e that held for a one-slot engine; with
+four slots some requests took a different greedy token part-way, and
+``generate()`` at batch 4 differs from batch 1 as well, so the multi-slot
+program's rounding differs from the batch-1 one.  ``chip_smoke.py``
+requires each such first difference to be a near-tie of the prefill's
+logits.
 """
 from __future__ import annotations
 

@@ -5,10 +5,9 @@ State layout (flat dicts keyed by param name):
 
 Any of master/mu/nu may be *split* along the stacked-layer dim into
 ``{"host": arr[:k], "dev": arr[k:]}`` to realize Mist's WO/OO offload ratios:
-the host part carries a ``pinned_host`` memory-kind sharding, and XLA's
-latency-hiding scheduler streams it through HBM during the (per-layer-
-decoupled) optimizer update — the TPU analogue of Mist's repositioned
-optimizer steps (paper §5.1).
+the host part carries a ``pinned_host`` memory-kind sharding, and the
+optimizer update stages it through HBM one tensor at a time — the TPU
+analogue of Mist's repositioned optimizer steps (paper §5.1).
 """
 from __future__ import annotations
 
@@ -18,7 +17,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.plan import StageConfig
 from repro.models.common import Axes, Params
 from repro.parallel.sharding import LAYER_AXES
@@ -122,29 +120,35 @@ def adam_update(state: Dict[str, Any], grads: Params, acfg: AdamConfig,
 
     ``shardings`` (same structure as state) is required when any state leaf
     is host-offloaded: host slices are explicitly staged through device
-    memory for the update, then placed back (XLA's latency-hiding scheduler
-    overlaps these per-tensor transfers — the decoupled optimizer step)."""
+    memory for the update, then placed back.  With any leaf offloaded the
+    tensors are updated one at a time (an ``optimization_barrier`` chain),
+    so only one tensor's staged slices and f32 temporaries occupy device
+    memory at once; plans with no offload leave the order to XLA."""
     step = state["step"] + 1
     gnorm = global_norm(grads)
     clip = jnp.minimum(1.0, acfg.grad_clip / (gnorm + 1e-12))
     c1 = 1.0 - acfg.b1 ** step.astype(jnp.float32)
     c2 = 1.0 - acfg.b2 ** step.astype(jnp.float32)
 
-    _hk = compat.host_memory_kind()
-
     def to_dev(x, entry, name):
-        if _hk is None:         # no host memory space: already resident
-            return x
         sh = shardings[entry][name]["host"].with_memory_kind("device")
         return jax.device_put(x, sh)
 
     def to_host(x, entry, name):
-        if _hk is None:
-            return x
         return jax.device_put(x, shardings[entry][name]["host"])
 
     new_params, new_master, new_mu, new_nu = {}, {}, {}, {}
+    serial = any(is_split(leaf) for entry in ("master", "mu", "nu")
+                 for leaf in state[entry].values())
+    done = None
     for name, g in grads.items():
+        m, mu, nu = state["master"][name], state["mu"][name], state["nu"][name]
+        if serial and done is not None:
+            # one tensor at a time: this tensor's inputs (its host slices
+            # among them) wait for the previous tensor's update, so at most
+            # one tensor's f32 update temporaries are live in device memory
+            done, (g, m, mu, nu) = jax.lax.optimization_barrier(
+                (done, (g, m, mu, nu)))
         g = g.astype(jnp.float32) * clip
 
         def upd(m, mu, nu, gg):
@@ -154,7 +158,6 @@ def adam_update(state: Dict[str, Any], grads: Params, acfg: AdamConfig,
             m = m - acfg.lr * (upd_ + acfg.weight_decay * m)
             return m, mu, nu
 
-        m, mu, nu = state["master"][name], state["mu"][name], state["nu"][name]
         if is_split(m) or is_split(mu):
             kh = (m["host"].shape[0] if is_split(m)
                   else mu["host"].shape[0])
@@ -186,6 +189,8 @@ def adam_update(state: Dict[str, Any], grads: Params, acfg: AdamConfig,
             full_m, new_mu[name], new_nu[name] = upd(m, mu, nu, g)
             new_master[name] = full_m
         new_params[name] = full_m.astype(state["params"][name].dtype)
+        done = (new_params[name], new_master[name], new_mu[name],
+                new_nu[name])
 
     new_state = {"step": step, "params": new_params, "master": new_master,
                  "mu": new_mu, "nu": new_nu}

@@ -36,8 +36,7 @@ class CompiledStep:
 
 
 def _is_host_leaf(s) -> bool:
-    hk = compat.host_memory_kind()
-    return hk is not None and getattr(s, "memory_kind", None) == hk
+    return getattr(s, "memory_kind", None) == compat.HOST_MEMORY_KIND
 
 
 def _constrain_device_leaves(tree, shardings):
@@ -108,17 +107,22 @@ def make_train_step(model: Model, plan: Plan, mesh: Mesh,
             return new_state, metrics
 
     batch_sh = None  # filled by caller via batch_shardings fn
-    # NOTE: no explicit out_shardings — XLA's SPMD partitioner rejects them
-    # when any output lives in pinned_host.  Host-offloaded slices are moved
-    # back out *outside* the jit boundary (the post-step swap-out; a no-op
-    # when nothing is offloaded).
+    has_host = any(_is_host_leaf(s) for s in jax.tree.leaves(st_shardings))
+    # out_shardings keep every state leaf where the plan put it, host-
+    # offloaded slices included.  The CPU backend cannot place jit outputs
+    # in pinned_host: there the host slices leave the step in device
+    # memory, are moved back after it, and are not donated (a donated
+    # pinned_host buffer would alias an output the runtime takes for
+    # device memory).
+    host_after = has_host and jax.default_backend() == "cpu"
+    jit_kw = {} if host_after else {"out_shardings": (st_shardings, None)}
     jit_fn = jax.jit(
         train_step,
         in_shardings=(st_shardings, None),
-        donate_argnums=(0,) if donate else (),
+        donate_argnums=(0,) if donate and not host_after else (),
+        **jit_kw,
     )
-    has_host = any(_is_host_leaf(s) for s in jax.tree.leaves(st_shardings))
-    if has_host:
+    if host_after:
         def fn(state, batch):
             new_state, metrics = jit_fn(state, batch)
             return jax.device_put(new_state, st_shardings), metrics
@@ -132,15 +136,13 @@ def make_train_step(model: Model, plan: Plan, mesh: Mesh,
 def init_sharded_state(model: Model, plan: Plan, mesh: Mesh, rng: jax.Array,
                        lowered: Optional[LoweredPlan] = None
                        ) -> Tuple[Dict[str, Any], Any]:
-    """Materialize a sharded TrainState on the mesh."""
+    """Materialize a sharded TrainState on the mesh (the pipeline step's
+    stage-sharded layout when the plan has several stages)."""
     low = lowered or lower_plan(model.cfg, None, plan, mesh)
     stage = plan.stages[0]
     axes_table = low.axes_table
-    shardings = low.state_shardings()
-
-    def build():
-        params, _ = model.init(rng)
-        return OPT.init_state(params, axes_table, stage)
+    shardings = (low.pipeline_state_shardings() if plan.num_stages > 1
+                 else low.state_shardings())
 
     # jit-init with device-memory shardings (XLA SPMD rejects host memory
     # kinds on freshly-created values), then move host-offloaded slices out.
@@ -148,7 +150,13 @@ def init_sharded_state(model: Model, plan: Plan, mesh: Mesh, rng: jax.Array,
         lambda s: NamedSharding(s.mesh, s.spec) if isinstance(
             s, NamedSharding) else s, shardings,
         is_leaf=lambda x: isinstance(x, NamedSharding))
-    state = jax.jit(build, out_shardings=dev_shardings)()
+    # the params are materialized before the master copy is cast from
+    # them: within one program the TPU compiler may drop the f32 -> bf16
+    # -> f32 rounding (excess precision), leaving master != params
+    params = jax.jit(lambda: model.init(rng)[0],
+                     out_shardings=dev_shardings["params"])()
+    state = jax.jit(lambda p: OPT.init_state(p, axes_table, stage),
+                    out_shardings=dev_shardings, donate_argnums=0)(params)
     needs_move = any(_is_host_leaf(s) for s in jax.tree.leaves(shardings))
     if needs_move:
         state = jax.device_put(state, shardings)

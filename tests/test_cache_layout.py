@@ -21,6 +21,7 @@ The serve-side twin of tests/test_state_layout.py.  Contracts pinned:
 """
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -28,7 +29,6 @@ try:
 except ImportError:                                  # pragma: no cover
     HAVE_HYPOTHESIS = False
 
-from repro import compat
 from repro.configs.base import ShapeConfig, get_arch, list_archs
 from repro.core import symbolic as S
 from repro.core.costmodel import ServeCostModel, estimate_serve_plan
@@ -53,7 +53,7 @@ def _concrete_via_specs(arch, batch, max_len, dp, tp, kv):
     plan = single_stage_plan(cfg.num_layers, dp=dp, tp=tp, micro_batch=1,
                              grad_accum=1, zero=0, ckpt_layers=0,
                              kv_cache_dtype=kv)
-    mesh = compat.abstract_mesh((dp, tp), ("data", "model"))
+    mesh = AbstractMesh((dp, tp), ("data", "model"))
     low = lower_plan(cfg, None, plan, mesh)
     shape = ShapeConfig("serve", max_len, batch, "decode")
     return stage_cache_bytes(low, shape)
@@ -176,7 +176,7 @@ def test_estimate_serve_plan_matches_report_bitwise(arch, dp, tp, zero, kv):
     plan = single_stage_plan(cfg.num_layers, dp=dp, tp=tp, micro_batch=1,
                              grad_accum=1, zero=zero, ckpt_layers=0,
                              kv_cache_dtype=kv)
-    mesh = compat.abstract_mesh((dp, tp), ("data", "model"))
+    mesh = AbstractMesh((dp, tp), ("data", "model"))
     for kind, field in (("decode", "mem_decode"), ("prefill", "mem_prefill")):
         shape = ShapeConfig("serve", 48, 4, kind)
         est = estimate_serve_plan(cfg, shape, plan)

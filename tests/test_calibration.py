@@ -273,3 +273,56 @@ def test_measure_and_fit_end_to_end():
     assert (report["mean_err_fitted"]
             <= report["mean_err_uncalibrated"] + 1e-12)
     assert report["n_cells"] == 1
+
+
+class TestShippedTpuProfile:
+    """The v5e profile shipped in calibration/profiles/: its memory terms
+    keep the tuner off the plans the chip's compiler refused."""
+
+    @pytest.fixture
+    def tpu(self, tmp_path, monkeypatch):
+        monkeypatch.setenv("REPRO_CALIBRATION_DIR", str(tmp_path))
+        monkeypatch.delenv("REPRO_CALIBRATION_PROFILE", raising=False)
+        return load_profile("tpu")
+
+    def test_resolution(self, tpu, tmp_path):
+        assert tpu.platform == "tpu"
+        assert dict(tpu.cost)["act_overhead"] > 0
+        # a user's fitted file for the platform wins over the shipped one
+        mine = CalibrationProfile.make(platform="tpu",
+                                       cost={"act_overhead": 0.5})
+        mine.save(tmp_path / "tpu.json")
+        assert load_profile("tpu") == mine
+
+    def test_overhead_term_predicted_equals_lowered(self, tpu):
+        from repro.configs.base import ShapeConfig
+        from repro.core.plan import single_stage_plan
+        from repro.lowering import memory_consistency
+        cfg = get_arch("granite-3-8b").replace(num_layers=4)
+        plan = single_stage_plan(4, dp=1, tp=1, micro_batch=1, grad_accum=8,
+                                 zero=0, oo=0.875, ckpt_layers=1)
+        shape = ShapeConfig("t", 4096, 8, "train")
+        mc = memory_consistency(cfg, shape, plan, profile=tpu)
+        base = memory_consistency(cfg, shape, plan)
+        assert mc["within_tol"]
+        over = mc["terms"]["overhead"]
+        assert over["predicted"] == pytest.approx(over["lowered"], rel=1e-9)
+        assert base["terms"]["overhead"]["lowered"] == 0.0
+        f = dict(tpu.cost)["act_overhead"]
+        temps = sum(base["terms"][k]["lowered"]
+                    for k in ("act", "transient", "logits"))
+        assert over["lowered"] == pytest.approx(f * temps)
+
+    def test_one_chip_plan_is_the_one_that_compiles(self, tpu):
+        """granite-3-8b at 4 layers, seq 4096, batch 8, one v5e: the
+        default constants pick ckpt_layers=1, which the v5e compiler
+        refused (17.78 GiB of 15.75); the profile picks the fully
+        rematerialized plan it accepted."""
+        from repro.configs.base import ShapeConfig
+        from repro.core.tuner import tune
+        cfg = get_arch("granite-3-8b").replace(num_layers=4)
+        shape = ShapeConfig("t", 4096, 8, "train")
+        plain = tune(cfg, shape, 1, workers=1).plan
+        tuned = tune(cfg, shape, 1, workers=1, profile=tpu).plan
+        assert plain.stages[0].ckpt_layers == 1
+        assert tuned.stages[0].ckpt_layers == 4

@@ -193,14 +193,14 @@ def test_kernel_sweep_worker_identity():
 def test_tuned_plan_lowers_with_kernel_exec_config():
     """plan.kernel threads through lower_plan into every stage's
     ExecConfig (and the serve config)."""
-    from repro import compat
+    from jax.sharding import AbstractMesh
     from repro.lowering.lower import lower_plan
     arch = get_arch("granite-3-8b").reduced()
     plan = MistTuner(_spec(arch)).tune().plan.replace(
         kernel=KernelConfig(1024, 512, 128, 256), attn_impl="pallas",
         use_pallas=True)
     st = plan.stages[0]
-    mesh = compat.abstract_mesh((st.dp, st.tp), ("data", "model"))
+    mesh = AbstractMesh((st.dp, st.tp), ("data", "model"))
     low = lower_plan(arch, None, plan, mesh)
     ec = low.stages[0].exec_cfg
     assert (ec.attn_q_block, ec.attn_kv_block, ec.rmsnorm_block,

@@ -17,6 +17,7 @@ import pathlib
 import subprocess
 import sys
 
+import jax
 import pytest
 
 from repro import compat
@@ -144,7 +145,7 @@ def test_lowering_matches_frozen_reference(space, arch):
     cfg = get_arch(arch)
     plan = golden_plan_for(space, arch)
     st = plan.stages[0]
-    mesh = compat.abstract_mesh((st.dp, st.tp), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((st.dp, st.tp), ("data", "model"))
     low = lower_plan(cfg, None, plan, mesh)
 
     ma, pspecs, gspecs, ospecs = frozen_single_stage_tables(cfg, plan, mesh)
@@ -166,7 +167,7 @@ def test_lowering_pipeline_tables_match_frozen_reference():
                                wo=0.5, oo=0.25)
                    for i in range(2))
     plan = Plan(grad_accum=2, stages=stages)
-    mesh = compat.abstract_mesh((2, 2, 2), ("stage", "data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 2, 2), ("stage", "data", "model"))
     low = lower_plan(cfg, None, plan, mesh)
     specs, manual = frozen_pipeline_specs(cfg, plan, mesh)
     assert low.pipeline_param_specs == specs
@@ -186,10 +187,10 @@ def test_lower_plan_rejects_mismatched_mesh():
                              grad_accum=2, zero=1)
     with pytest.raises(ValueError, match=r"plan/mesh mismatch.*\(4, 2\)"):
         lower_plan(cfg, None, plan,
-                   compat.abstract_mesh((2, 4), ("data", "model")))
+                   jax.sharding.AbstractMesh((2, 4), ("data", "model")))
     # the matching view lowers fine
     lower_plan(cfg, None, plan,
-               compat.abstract_mesh((4, 2), ("data", "model")))
+               jax.sharding.AbstractMesh((4, 2), ("data", "model")))
 
 
 def test_lower_plan_tp1_fold_stays_legal():
@@ -199,11 +200,11 @@ def test_lower_plan_tp1_fold_stays_legal():
     plan = single_stage_plan(cfg.num_layers, dp=8, tp=1, micro_batch=1,
                              grad_accum=2, zero=1)
     low = lower_plan(cfg, None, plan,
-                     compat.abstract_mesh((4, 2), ("data", "model")))
+                     jax.sharding.AbstractMesh((4, 2), ("data", "model")))
     assert low.stages[0].mesh_axes.tp is None
     with pytest.raises(ValueError, match="plan/mesh mismatch"):
         lower_plan(cfg, None, plan,
-                   compat.abstract_mesh((2, 2), ("data", "model")))
+                   jax.sharding.AbstractMesh((2, 2), ("data", "model")))
 
 
 def test_lower_plan_rejects_stage_mismatch():
@@ -214,10 +215,10 @@ def test_lower_plan_rejects_stage_mismatch():
     plan = Plan(grad_accum=2, stages=stages)
     with pytest.raises(ValueError, match="no 'stage' axis"):
         lower_plan(cfg, None, plan,
-                   compat.abstract_mesh((2, 2), ("data", "model")))
+                   jax.sharding.AbstractMesh((2, 2), ("data", "model")))
     with pytest.raises(ValueError, match="'stage' axis has size 4"):
         lower_plan(cfg, None, plan,
-                   compat.abstract_mesh((4, 2, 2),
+                   jax.sharding.AbstractMesh((4, 2, 2),
                                         ("stage", "data", "model")))
 
 
@@ -256,8 +257,7 @@ def test_state_shardings_tree_on_concrete_mesh():
             spec = SH.opt_spec(n, state["params"][n].shape, axes_table[n],
                                mesh, ma, zero=stage.zero, ep_ok=ep_ok)
             if is_split(leaf):
-                host = (NamedSharding(mesh, spec, memory_kind=hk)
-                        if hk else NamedSharding(mesh, spec))
+                host = NamedSharding(mesh, spec, memory_kind=hk)
                 e[n] = {"host": host, "dev": NamedSharding(mesh, spec)}
             else:
                 e[n] = NamedSharding(mesh, spec)
@@ -367,7 +367,7 @@ def test_memory_report_offload_moves_bytes_to_host():
     """WO/OO/AO ratios move state/activation bytes off-device; device
     total shrinks accordingly."""
     cfg = get_arch("granite-3-8b")
-    mesh = compat.abstract_mesh((1, 8), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((1, 8), ("data", "model"))
 
     def rep(**kw):
         plan = single_stage_plan(40, dp=1, tp=8, micro_batch=4,
@@ -393,7 +393,7 @@ def test_dryrun_analytic_helpers_in_process():
     from repro.launch import dryrun as DR
 
     cfg = get_arch("granite-3-8b")
-    mesh = compat.abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     b1 = DR.state_bytes_per_device(cfg, mesh, 1)
     b2 = DR.state_bytes_per_device(cfg, mesh, 2)
     b3 = DR.state_bytes_per_device(cfg, mesh, 3)

@@ -257,7 +257,6 @@ def test_tuned_serve_plan_generates_identical_tokens():
     """The acceptance criterion, end to end on a reduced golden arch:
     generate() under the serve tuner's winning plan emits exactly the
     token ids the hand-built dp-only baseline emits."""
-    from repro import compat
     from repro.core.plan import single_stage_plan
     from repro.launch.mesh import make_host_mesh
     from repro.launch.serve import generate, tuned_serve_plan
@@ -277,7 +276,7 @@ def test_tuned_serve_plan_generates_identical_tokens():
     for name, p in (("base", base), ("tuned", plan)):
         st = p.stages[0]
         mesh = make_host_mesh(st.dp, st.tp)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params, _ = model.init(jax.random.PRNGKey(0))
             prompts = jax.random.randint(jax.random.PRNGKey(1),
                                          (batch, plen), 0,

@@ -31,7 +31,6 @@ try:
 except ImportError:          # property tests skip; example tests still run
     HAVE_HYPOTHESIS = False
 
-from repro import compat
 from repro.configs.base import ShapeConfig, get_arch
 from repro.core.plan import Plan, single_stage_plan
 from repro.launch.mesh import make_host_mesh
@@ -239,7 +238,7 @@ def _run_family(fam, arch, kv_dtype="bf16"):
                              kv_cache_dtype=kv_dtype, page_size=PAGE)
     mesh = make_host_mesh(1, 1)
     low = lower_plan(cfg, None, plan, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params, _ = model.init(jax.random.PRNGKey(0))
         kv8 = kv_dtype == "int8"
         eng = ContinuousBatchingEngine(model, params, plan, mesh,
@@ -301,7 +300,7 @@ def test_paged_estimate_matches_memory_report():
     base = single_stage_plan(cfg.num_layers, dp=1, tp=1, micro_batch=1,
                              grad_accum=1, zero=0, ckpt_layers=0)
     shape = ShapeConfig("serve", 32, 2, "decode")
-    mesh = compat.abstract_mesh((1, 1), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((1, 1), ("data", "model"))
     seen = set()
     for ps in (0, 8, 16):
         plan = dataclasses.replace(base, page_size=ps)

@@ -28,7 +28,7 @@ def _mesh(dp=1, tp=1):
     if dp * tp <= len(jax.devices()):
         return compat.make_mesh((dp, tp), ("data", "model"))
     # spec-only tests: abstract meshes carry shapes without devices
-    return compat.abstract_mesh((dp, tp), ("data", "model"))
+    return jax.sharding.AbstractMesh((dp, tp), ("data", "model"))
 
 
 # -- choose_tp_dim / param_spec ------------------------------------------------

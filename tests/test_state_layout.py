@@ -23,6 +23,7 @@ import math
 
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -30,7 +31,6 @@ try:
 except ImportError:                                  # pragma: no cover
     HAVE_HYPOTHESIS = False
 
-from repro import compat
 from repro.configs.base import get_arch, list_archs
 from repro.core import symbolic as S
 from repro.lowering.state_layout import (CONCRETE_OPS, LAYER_AXES,
@@ -156,7 +156,7 @@ def test_layout_matches_spec_walk(arch, dp, tp, zero, wo, oo):
     cfg = get_arch(arch)
     plan = single_stage_plan(cfg.num_layers, dp=dp, tp=tp, micro_batch=1,
                              grad_accum=1, zero=zero, wo=wo, oo=oo)
-    mesh = compat.abstract_mesh((dp, tp), ("data", "model"))
+    mesh = AbstractMesh((dp, tp), ("data", "model"))
     low = lower_plan(cfg, None, plan, mesh)
     want = _state_walk(low, low.stages[0], 1.0)
     got = stage_layout_terms(low, 0)
@@ -176,7 +176,7 @@ def test_layout_matches_spec_walk_pipeline():
                                zero=2, ckpt_layers=20, wo=0.5, oo=0.25)
                    for _ in range(2))
     plan = Plan(grad_accum=2, stages=stages)
-    mesh = compat.abstract_mesh((2, 2, 2), ("stage", "data", "model"))
+    mesh = AbstractMesh((2, 2, 2), ("stage", "data", "model"))
     low = lower_plan(cfg, None, plan, mesh)
     for i, ls in enumerate(low.stages):
         want = _state_walk(low, ls, ls.stage.layers / plan.total_layers)

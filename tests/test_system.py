@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs.base import ShapeConfig, get_arch
 from repro.core.plan import single_stage_plan
 from repro.core.schedule import validate_plan
@@ -35,7 +34,7 @@ def test_tune_then_execute_reduced():
         ckpt_layers=min(tuned.ckpt_layers, rcfg.num_layers),
         oo=tuned.oo, ao=tuned.ao)
     mesh = make_host_mesh(1, 1)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_train_step(model, plan, mesh, donate=False)
         state, _ = init_sharded_state(model, plan, mesh,
                                       jax.random.PRNGKey(0))

@@ -92,7 +92,7 @@ def measure_cell(arch: str, kind: str, *, reduced: bool,
             lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
             sds_tree, shardings)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             step = make_train_step(model, plan, mesh, lowered=low)
             state_abs = OPT.init_state(low.params_sds, low.axes_table,
