@@ -9,12 +9,13 @@
     and both return identical frontiers/objectives/plans, and
   * the parallel (S, G) sweep executor (`core/sweep.py`,
     `tune(..., workers=N)`) vs the serial compiled engine (`workers=0`):
-    G-collapsed hypothesis sweeps + across-unit batched refinement +
-    per-cell MILPs on a persistent forked worker pool, with the frontier
-    memo sharded across workers and merged at the join.  Selected plans
-    are asserted byte-identical.  Reported cold (worker caches cleared
-    between runs) and warm (the persistent workers' knob-tuple caches
-    left alone — what repeated `tune()` calls in one session observe).
+    G-collapsed hypothesis sweeps on a persistent forked worker pool +
+    across-unit batched refinement, with the frontier memo sharded across
+    workers and merged at the join (the per-cell MILPs are solved in the
+    parent).  Selected plans are asserted byte-identical.  Reported cold
+    (worker caches cleared between runs) and warm (the persistent
+    workers' knob-tuple caches left alone — what repeated `tune()` calls
+    in one session observe).
 
 A fourth measurement compares the tape *evaluation backends* on one large
 candidate grid (`run_backend_speedup`): the numpy instruction loop vs the
@@ -119,9 +120,9 @@ def run_parallel_speedup(size: str = "6.7b", n_dev: int = 32, gbs: int = 64,
 
     Cold rows clear the persistent workers' knob-tuple caches between
     runs, so they measure the per-tune executor speedup (parallel sweeps
-    + batched refinement + parallel MILPs).  The warm row leaves the
-    worker caches alone, which is what a session issuing many `tune()`
-    calls actually experiences.  Byte-identical plans are asserted
+    + batched refinement).  The warm row leaves the worker caches alone,
+    which is what a session issuing many `tune()` calls actually
+    experiences.  Byte-identical plans are asserted
     between every serial and parallel invocation."""
     from repro.core.sweep import clear_worker_caches, warm_pool
     cfg, shape = gpt_config(size), train_shape(gbs, 2048)

@@ -227,13 +227,15 @@ def _start_method():
     fork is deliberate: forkserver/spawn re-import ``__main__`` in every
     worker, which re-executes unguarded user scripts and breaks
     stdin/REPL sessions outright — far worse failure modes than fork's
-    theoretical hazard (forking a parent whose XLA/BLAS threads hold an
-    internal lock mid-fork).  That hazard is narrow here: workers never
-    touch jax (the sweep path is numpy/scipy only), OpenBLAS and glibc
-    malloc register fork handlers, and the full jax-initialized test
-    suite exercises this pool without incident.  If a fork-related hang
-    is ever suspected, ``workers=1`` (or 0) sidesteps the pool entirely
-    with identical results."""
+    hazard: a child inherits a parent library's thread state but none of
+    its threads.  That hazard is real, not theoretical: HiGHS
+    (``scipy.optimize.milp``) sets up its thread scheduler on its first
+    solve, and a child forked after the parent had solved one MILP waited
+    forever on the missing threads (seen with scipy 1.17).  So forked
+    workers run only the hypothesis sweeps, which are numpy-only and
+    never touch jax or HiGHS; the per-cell MILPs are always solved in the
+    tuner's own process.  ``workers=1`` (or 0) sidesteps the pool
+    entirely with identical results."""
     return "fork" if "fork" in mp.get_all_start_methods() else None
 
 
@@ -341,33 +343,6 @@ def clear_worker_caches() -> bool:
     return ok
 
 
-def _milp_task(payload: bytes):
-    from repro.core.inter_stage import solve_milp
-    cands, total_layers, total_devices, G = pickle.loads(payload)
-    return solve_milp(cands, total_layers=total_layers,
-                      total_devices=total_devices, G=G)
-
-
-def solve_cells(jobs, *, total_layers: int, total_devices: int,
-                workers: int = 1) -> Dict[Tuple[int, int], object]:
-    """Solve the per-cell inter-stage MILPs (paper Eq. 2-3), optionally on
-    the worker pool — each cell's MILP is independent and HiGHS is
-    deterministic, so placement doesn't affect results.
-
-    jobs: [(S, G, cands)] with cands the per-stage candidate lists."""
-    n = min(max(1, int(workers)), len(jobs))
-    if n > 1 and _start_method() is not None:
-        pool = _get_pool(n)
-        payloads = [pickle.dumps((cands, total_layers, total_devices, G))
-                    for _S, G, cands in jobs]
-        sols = pool.map(_milp_task, payloads)
-        return {(S, G): sol for (S, G, _), sol in zip(jobs, sols)}
-    from repro.core.inter_stage import solve_milp
-    return {(S, G): solve_milp(cands, total_layers=total_layers,
-                               total_devices=total_devices, G=G)
-            for S, G, cands in jobs}
-
-
 def _sweep_local(tuner, plan: SweepPlan, knobs,
                  unit_idxs: Sequence[int]) -> Tuple[list, int, int, int]:
     """In-process `_sweep_units` on the parent tuner, with the same
@@ -446,8 +421,8 @@ def prefetch_frontiers(tuner, cells: Sequence[Tuple[int, int]], knobs,
     use_pool = len(shards) > 1 and _start_method() is not None
     if use_pool:
         # size the pool at the requested worker count even when this
-        # plan sharded smaller, so a later phase (solve_cells) never has
-        # to recreate the pool and throw the warm worker caches away
+        # plan sharded smaller, so a later tune() never has to recreate
+        # the pool and throw the warm worker caches away
         pool = _get_pool(workers)
         payloads = [pickle.dumps((tuner.spec, knobs, plan, s))
                     for s in shards]

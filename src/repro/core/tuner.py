@@ -394,35 +394,20 @@ class MistTuner:
             self._n_swept += sweep_stats.n_swept
             if store is not None:
                 store.flush(self, cells, knobs)
-        # gather each cell's candidate lists (all frontier-memo reads after
-        # a prefetch), solve the independent per-cell MILPs — on the worker
-        # pool when the executor is parallel — then reduce in loop order,
+        # each cell's candidate lists are frontier-memo reads after a
+        # prefetch; its MILP is solved in this process, never in a forked
+        # worker (see sweep._start_method).  Cells reduce in loop order,
         # which keeps tie-breaking identical to the serial engine.
-        sols: Dict[Tuple[int, int], Optional[InterStageSolution]] = {}
-        milp_jobs: List[Tuple[int, int, List[List[StageCand]]]] = []
-        cells = self._cells()
-        for S, G in cells:
+        for S, G in self._cells():
             if spec.space == "uniform" and S > 1:
-                sols[(S, G)] = self._solve_uniform(S, G, knobs)
+                sol = self._solve_uniform(S, G, knobs)
             else:
                 cands = self._cands_for(S, G, knobs)
                 if any(not cs for cs in cands):
                     continue
-                milp_jobs.append((S, G, cands))
-        if milp_jobs:
-            n_milp += len(milp_jobs)
-            if sweep_stats is not None and spec.workers > 1:
-                from repro.core.sweep import solve_cells
-                sols.update(solve_cells(
-                    milp_jobs, total_layers=spec.arch.num_layers,
-                    total_devices=spec.n_devices, workers=spec.workers))
-            else:
-                for S, G, cands in milp_jobs:
-                    sols[(S, G)] = solve_milp(
-                        cands, total_layers=spec.arch.num_layers,
-                        total_devices=spec.n_devices, G=G)
-        for S, G in cells:
-            sol = sols.get((S, G))
+                n_milp += 1
+                sol = solve_milp(cands, total_layers=spec.arch.num_layers,
+                                 total_devices=spec.n_devices, G=G)
             if sol is None:
                 continue
             per_sg.append((S, G, sol.objective))

@@ -17,7 +17,11 @@ def _run(code: str, devices: int = 8, timeout: int = 900) -> str:
                 if k not in env and k != "XLA_FLAGS"})
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=timeout, env=env)
-    assert r.returncode == 0, f"stderr:\n{r.stderr[-3000:]}"
+    # head and tail: an XLA "Check failed" line comes first, then the
+    # stack frames that would push it out of a tail alone
+    err = r.stderr if len(r.stderr) <= 3000 else \
+        f"{r.stderr[:1500]}\n[...]\n{r.stderr[-1500:]}"
+    assert r.returncode == 0, f"stderr:\n{err}"
     return r.stdout
 
 

@@ -2,22 +2,28 @@
 G-collapsed multi-G sweeps, the knob-tuple tape cache, and `Tape.run`
 scratch buffers must all be *bitwise invisible* — identical frontiers,
 objectives, and plans to the plain serial compiled engine."""
+import json
+import os
+import pathlib
+import signal
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
 from repro.configs.base import ShapeConfig, get_arch
 from repro.core.costmodel import StageCostModel
 from repro.core.intra_stage import tune_stage, tune_stage_multi_g
-from repro.core.inter_stage import solve_milp
 from repro.core.schedule import candidate_grid
-from repro.core.sweep import (plan_units, prefetch_frontiers, solve_cells,
-                              _shard_units)
+from repro.core.sweep import plan_units, prefetch_frontiers, _shard_units
 from repro.core.symbolic import Sym, ceil, compile_tape, smax, smin, where
 from repro.core.tuner import MistTuner, TuneSpec, _space_knobs, tune
 
 ARCH = "granite-3-8b"
 SHAPE = ShapeConfig("t", 4096, 32, "train")
 SMALL = dict(stage_counts=(1, 2), grad_accums=(2, 4))
+SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
 
 
 def _spec(space="mist", workers=1, **kw):
@@ -198,33 +204,53 @@ def test_time_tape_is_g_and_inflight_independent():
     assert "G" not in mem_syms
 
 
-# -- parallel MILP phase ------------------------------------------------------
+# -- MILPs after a fork --------------------------------------------------------
 
 
-def test_solve_cells_matches_serial_milp():
-    cfg = get_arch(ARCH)
-    spec = _spec()
-    knobs = _space_knobs("mist", cfg.num_layers)
-    tuner = MistTuner(spec)
-    prefetch_frontiers(tuner, tuner._cells(), knobs, workers=1)
-    jobs = []
-    for S, G in tuner._cells():
-        cands = tuner._cands_for(S, G, knobs)
-        if not any(not cs for cs in cands):
-            jobs.append((S, G, cands))
-    assert jobs
-    par = solve_cells(jobs, total_layers=cfg.num_layers, total_devices=16,
-                      workers=4)
-    for S, G, cands in jobs:
-        ser = solve_milp(cands, total_layers=cfg.num_layers,
-                         total_devices=16, G=G)
-        p = par[(S, G)]
-        if ser is None:
-            assert p is None
-            continue
-        assert p.objective == ser.objective
-        assert [(c.layers, c.n_devices, c.t, c.d) for c in p.selection] \
-            == [(c.layers, c.n_devices, c.t, c.d) for c in ser.selection]
+MILP_AFTER_FORK = r"""
+import json
+from repro.configs.base import ShapeConfig, get_arch
+from repro.core.tuner import tune
+
+cfg = get_arch("granite-3-8b")
+shape = ShapeConfig("t", 4096, 32, "train")
+small = dict(stage_counts=(1, 2), grad_accums=(2, 4))
+
+def key(rep):
+    return repr((rep.objective, rep.plan, rep.best_S, rep.best_G,
+                 tuple(rep.per_sg), rep.n_milp))
+
+same = {}
+for space in ("megatron", "zero"):
+    serial = tune(cfg, shape, 16, space=space, workers=0, **small)
+    pooled = tune(cfg, shape, 16, space=space, workers=4, **small)
+    same[space] = key(serial) == key(pooled)
+print(json.dumps(same))
+"""
+
+
+def test_pool_tune_after_in_process_milp_returns():
+    """A process that has already solved a MILP in-process (HiGHS set up
+    its thread scheduler) then tunes with a forked pool, as any session
+    that tunes twice does.  The tune must return, with the serial plans.
+    Run in a fresh interpreter so the HiGHS and pool state are this
+    test's own, and under a timeout so a hang fails instead of stalling.
+    The child leads its own process group, so a timeout also kills the
+    pool workers it forked."""
+    env = {**os.environ, "PYTHONPATH": SRC}
+    p = subprocess.Popen([sys.executable, "-c", MILP_AFTER_FORK],
+                         stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                         text=True, env=env, start_new_session=True)
+    try:
+        out, err = p.communicate(timeout=120)
+    except subprocess.TimeoutExpired:
+        os.killpg(p.pid, signal.SIGKILL)
+        p.communicate()
+        pytest.fail("tune(workers=4) did not return within 120 s of an "
+                    "in-process MILP solve")
+    assert p.returncode == 0, f"stderr:\n{err[-3000:]}"
+    assert json.loads(out.strip().splitlines()[-1]) == \
+        {"megatron": True, "zero": True}
 
 
 # -- Tape scratch buffers -----------------------------------------------------
