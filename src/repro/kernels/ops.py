@@ -6,11 +6,16 @@
                custom-VJP blocked backward (O(block) intermediates) — the
                lowering-compatible stand-in for the Pallas kernel (used by
                the dry-run on the CPU host platform).
-  - "pallas":  the Pallas TPU kernel forward (interpret=True off-TPU) with
-               the same blocked backward.
+  - "pallas":  the Pallas TPU kernels (interpret=True off-TPU): the forward,
+               and a backward of lse, dK/dV and dQ kernels that keep every
+               score tile in VMEM.  Shapes those kernels do not tile
+               (Sq != Sk, head_dim or a block off the 128-lane tiling) take
+               the blocked backward; ``backward_paths`` counts, at trace
+               time, which backward each ``_flash`` took.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 from typing import Optional, Tuple
@@ -19,11 +24,16 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import (flash_attention_bwd,
+                                           flash_attention_fwd, flash_lse)
 from repro.kernels.rmsnorm import rmsnorm as rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 DEFAULT_BLOCK = 512
+LANES = 128
+
+# trace-time count of the backward each ``_flash`` took: "pallas" or "jnp"
+backward_paths: collections.Counter = collections.Counter()
 
 
 def _on_tpu() -> bool:
@@ -119,26 +129,44 @@ def _flash(q, k, v, causal: bool, scale: float, q_block: int, kv_block: int,
     return out
 
 
+def _pallas_bwd(q, k, q_block: int, kv_block: int) -> bool:
+    """Whether the Pallas backward kernels tile these shapes: self-attention
+    (Sq == Sk), head_dim and both blocks on the 128-lane tiling, blocks
+    dividing the sequence."""
+    s, d = q.shape[1:]
+    return (k.shape[1] == s and d % LANES == 0 and q_block % LANES == 0
+            and kv_block % LANES == 0 and s % q_block == 0
+            and s % kv_block == 0)
+
+
 def _flash_fwd_rule(q, k, v, causal, scale, q_block, kv_block, use_pallas):
-    if use_pallas:
-        out = flash_attention_fwd(q, k, v, causal=causal, scale=scale,
-                                  q_block=min(q_block, q.shape[1]),
-                                  kv_block=min(kv_block, k.shape[1]),
-                                  interpret=not _on_tpu())
-        # lse recomputed cheaply for the bwd (flash-style recompute)
-        _, lse = _blocked_fwd(q, k, v, causal, scale,
-                              min(kv_block, k.shape[1]))
+    q_block, kv_block = min(q_block, q.shape[1]), min(kv_block, k.shape[1])
+    if not use_pallas:
+        out, lse = _blocked_fwd(q, k, v, causal, scale, kv_block)
+        return out, (q, k, v, out, lse)
+    out = flash_attention_fwd(q, k, v, causal=causal, scale=scale,
+                              q_block=q_block, kv_block=kv_block,
+                              interpret=not _on_tpu())
+    # flash-style recompute: the backward needs each row's log-sum-exp
+    if _pallas_bwd(q, k, q_block, kv_block):
+        lse = flash_lse(q, k, causal=causal, scale=scale, q_block=q_block,
+                        kv_block=kv_block, interpret=not _on_tpu())
     else:
-        out, lse = _blocked_fwd(q, k, v, causal, scale,
-                                min(kv_block, k.shape[1]))
+        _, lse = _blocked_fwd(q, k, v, causal, scale, kv_block)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_rule(causal, scale, q_block, kv_block, use_pallas, res, dout):
     q, k, v, out, lse = res
-    dq, dk, dv = _blocked_bwd(q, k, v, out, lse, dout, causal, scale,
-                              min(kv_block, k.shape[1]))
-    return dq, dk, dv
+    q_block, kv_block = min(q_block, q.shape[1]), min(kv_block, k.shape[1])
+    if use_pallas and _pallas_bwd(q, k, q_block, kv_block):
+        backward_paths["pallas"] += 1
+        return flash_attention_bwd(q, k, v, out, lse, dout, causal=causal,
+                                   scale=scale, q_block=q_block,
+                                   kv_block=kv_block,
+                                   interpret=not _on_tpu())
+    backward_paths["jnp"] += 1
+    return _blocked_bwd(q, k, v, out, lse, dout, causal, scale, kv_block)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)

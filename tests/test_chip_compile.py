@@ -12,7 +12,10 @@ module is imported: only one process at a time may load the TPU's
 library, and the test workers each import every test file.
 """
 import functools
+import importlib.util
 import os
+import pathlib
+import re
 import signal
 
 import jax
@@ -167,3 +170,93 @@ def test_offloaded_train_step(one_chip, on_tpu, monkeypatch):
         for leaf in state[e].values()) > 0
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes > 0          # the state is donated
+
+
+FLASH_BWD_KERNELS = ("flash_lse", "flash_bwd_dkv", "flash_bwd_dq")
+ROOFLINE_READER = (pathlib.Path(__file__).resolve().parents[1] / "perfbench"
+                   / "metrics" / "flash_fwd_roofline.py")
+
+
+def _chip_calls(compiled):
+    """The program's text with operand shapes, as the profiler's trace
+    names each operation, and its Mosaic custom calls."""
+    from jax._src.lib import xla_client
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    opts.print_backend_config = False
+    text = "\n".join(m.to_string(opts) for m in
+                     compiled.runtime_executable().hlo_modules())
+    return text, [line for line in text.splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _kernel_name(call):
+    """The ``name=`` of a custom call's pallas_call, None if it has none."""
+    scope = re.search(r'op_name="([^"]*)"', call).group(1).split("/")
+    return scope[-2] if scope[-2] in FLASH_BWD_KERNELS else None
+
+
+def test_train_step_flash_backward_kernels(one_chip, on_tpu, monkeypatch):
+    """A reduced granite train step (head_dim 128, full remat) takes the
+    Pallas lse, dK/dV and dQ kernels; the benchmark's roofline reader
+    finds the forward kernel's calls and no other; and no f32
+    (batch*heads, seq, kv_block) score block is left outside the
+    kernels, where the blocked-jnp backward, compiled alongside, has
+    them."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.core.plan import single_stage_plan
+    from repro.lowering import lower_plan
+    from repro.models.zoo import build_model
+    from repro.training import optimizer as OPT
+    from repro.training.step import make_train_step
+
+    monkeypatch.syspath_prepend(str(ROOFLINE_READER.parents[1]))
+    spec = importlib.util.spec_from_file_location("flash_fwd_roofline",
+                                                  ROOFLINE_READER)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_arch("granite-3-8b").reduced().replace(head_dim=128)
+    plan = single_stage_plan(cfg.num_layers, dp=1, tp=1, micro_batch=2,
+                             grad_accum=2, zero=0, oo=0.0,
+                             ckpt_layers=cfg.num_layers)
+    plan = plan.replace(attn_impl="pallas", use_pallas=True)
+    seq, kv_block = 1024, plan.kernel.attn_kv_block
+    mesh = Mesh(np.array([one_chip._device]).reshape(1, 1),
+                ("data", "model"))
+    low = lower_plan(cfg, None, plan, mesh)
+    step = make_train_step(build_model(cfg), plan, mesh, lowered=low)
+    state = jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        OPT.init_state(low.params_sds, low.axes_table, plan.stages[0]),
+        step.state_shardings)
+    batch = {k: jax.ShapeDtypeStruct((4, seq), jnp.int32,
+                                     sharding=one_chip)
+             for k in ("tokens", "labels")}
+    scores = "f32[%d,%d,%d]" % (2 * cfg.num_heads, seq, kv_block)
+
+    def compile_step():
+        jax.clear_caches()          # trace again: count the path taken
+        with jax.set_mesh(mesh):
+            return _chip_calls(step.fn.lower(state, batch).compile())
+
+    before = ops.backward_paths.copy()
+    text, calls = compile_step()
+    assert ops.backward_paths - before == {"pallas": 1}
+    forward = [c for c in calls if _kernel_name(c) is None]
+    read = [c for c in calls if reader._shape(c) is not None]
+    assert read == forward and forward       # forward pass and remat
+    for name in FLASH_BWD_KERNELS:
+        assert [_kernel_name(c) for c in calls].count(name) == 1, name
+    assert scores not in text
+
+    # the same step on the blocked-jnp backward: the check above can fail
+    monkeypatch.setattr(ops, "_pallas_bwd", lambda *a: False)
+    jnp_text, jnp_calls = compile_step()
+    assert ops.backward_paths - before == {"pallas": 1, "jnp": 1}
+    assert [c for c in jnp_calls if reader._shape(c) is not None] \
+        == jnp_calls and len(jnp_calls) == len(forward)
+    assert scores in jnp_text

@@ -135,6 +135,174 @@ def test_pallas_flash_noncausal():
                                rtol=2e-5)
 
 
+# -- flash attention: Pallas backward kernels (interpret mode) -----------------
+
+
+def _path_counts(fn):
+    """Run ``fn`` and return (result, backward paths it took)."""
+    before = ops.backward_paths.copy()
+    out = fn()
+    return out, ops.backward_paths - before
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("qb,kb", [(128, 128), (256, 128)])
+def test_pallas_lse_matches_blocked(causal, qb, kb):
+    from repro.kernels.flash_attention import flash_lse
+    q, k, v = _qkv(2, 512, 512, 128, jnp.bfloat16)
+    scale = 1.0 / np.sqrt(128)
+    _, want = ops._blocked_fwd(q, k, v, causal, scale, kb)
+    got = flash_lse(q, k, causal=causal, q_block=qb, kv_block=kb,
+                    scale=scale, interpret=True)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("qb,kb", [(128, 128), (256, 128), (128, 256)])
+def test_pallas_bwd_matches_blocked(dtype, causal, qb, kb):
+    """The kernels against ``_blocked_bwd`` on the same residuals: the same
+    products in the same precisions, so only the order of f32 sums and
+    the dtype's final rounding differ."""
+    from repro.kernels.flash_attention import flash_attention_bwd
+    q, k, v = _qkv(2, 512, 512, 128, dtype)
+    dout = jax.random.normal(jax.random.PRNGKey(9), q.shape,
+                             jnp.float32).astype(dtype)
+    scale = 1.0 / np.sqrt(128)
+    out, lse = ops._blocked_fwd(q, k, v, causal, scale, kb)
+    want = ops._blocked_bwd(q, k, v, out, lse, dout, causal, scale, kb)
+    got = flash_attention_bwd(q, k, v, out, lse, dout, causal=causal,
+                              q_block=qb, kv_block=kb, scale=scale,
+                              interpret=True)
+    tol = 2e-3 if dtype == jnp.bfloat16 else 1e-5
+    for a, b in zip(want, got):
+        assert b.shape == a.shape and b.dtype == a.dtype
+        a = np.asarray(a, np.float32)
+        np.testing.assert_allclose(np.asarray(b, np.float32), a,
+                                   atol=tol * np.abs(a).max(), rtol=0)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("qb,kb", [(128, 128), (256, 128)])
+def test_pallas_flash_grad_matches_naive(causal, qb, kb):
+    """``_flash``'s VJP with the Pallas kernels against autodiff of the
+    naive reference, and the backward it took."""
+    bh, s, d = 2, 256, 128
+    q, k, v = _qkv(bh, s, s, d, jnp.float32)
+    scale = 1.0 / np.sqrt(d)
+
+    def f_ref(q, k, v):
+        return ref.naive_attention(q, k, v, causal=causal).sum()
+
+    def f_pallas(q, k, v):
+        return ops._flash(q, k, v, causal, scale, qb, kb, True).sum()
+
+    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    g_pal, paths = _path_counts(
+        lambda: jax.grad(f_pallas, argnums=(0, 1, 2))(q, k, v))
+    assert paths == {"pallas": 1}
+    for a, b in zip(g_ref, g_pal):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-4,
+                                   rtol=1e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("h,kv", [(4, 1), (8, 2)])
+def test_attention_pallas_bwd_gqa(dtype, h, kv):
+    """Through ``ops.attention`` with H = 4 KV heads per group: the Pallas
+    backward against autodiff of the naive path and against the blocked
+    backward."""
+    b, s, hd = 1, 256, 128
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (b, s, h, hd), jnp.float32).astype(dtype)
+    k = jax.random.normal(ks[1], (b, s, kv, hd), jnp.float32).astype(dtype)
+    v = jax.random.normal(ks[2], (b, s, kv, hd), jnp.float32).astype(dtype)
+
+    def grad(impl):
+        def f(q, k, v):
+            return ops.attention(q, k, v, impl=impl, q_block=128,
+                                 kv_block=128).astype(jnp.float32).sum()
+        return _path_counts(lambda: jax.grad(f, argnums=(0, 1, 2))(q, k, v))
+
+    g_pal, paths = grad("pallas")
+    assert paths == {"pallas": 1}
+    g_blk, paths = grad("blocked")
+    assert paths == {"jnp": 1}
+    g_ref = jax.grad(lambda q, k, v: ref.naive_attention(
+        q.reshape(b, s, kv, h // kv, hd).transpose(0, 2, 3, 1, 4)
+        .reshape(b * h, s, hd).astype(jnp.float32),
+        jnp.repeat(k, h // kv, axis=2).transpose(0, 2, 1, 3)
+        .reshape(b * h, s, hd).astype(jnp.float32),
+        jnp.repeat(v, h // kv, axis=2).transpose(0, 2, 1, 3)
+        .reshape(b * h, s, hd).astype(jnp.float32), causal=True).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
+    for a, bl, pa in zip(g_ref, g_blk, g_pal):
+        assert pa.shape == a.shape and pa.dtype == dtype
+        a = np.asarray(a, np.float32)
+        for got in (pa, bl):
+            np.testing.assert_allclose(np.asarray(got, np.float32), a,
+                                       atol=tol * np.abs(a).max(), rtol=0)
+
+
+@pytest.mark.parametrize("sq,sk,d,causal,block", [
+    (256, 256, 64, True, 128),      # head_dim off the 128-lane tiling
+    (64, 64, 128, True, 64),        # blocks shorter than 128 lanes
+    (128, 256, 128, False, 128),    # cross attention: Sq != Sk
+])
+def test_pallas_bwd_fallback_shapes(sq, sk, d, causal, block):
+    """Shapes the kernels do not tile take the blocked backward, and its
+    gradient is still autodiff's."""
+    q, k, v = _qkv(2, sq, sk, d, jnp.float32)
+    scale = 1.0 / np.sqrt(d)
+
+    def f_ref(q, k, v):
+        return ref.naive_attention(q, k, v, causal=causal).sum()
+
+    def f_pallas(q, k, v):
+        return ops._flash(q, k, v, causal, scale, block, block, True).sum()
+
+    g_pal, paths = _path_counts(
+        lambda: jax.grad(f_pallas, argnums=(0, 1, 2))(q, k, v))
+    assert paths == {"jnp": 1}
+    for a, b in zip(jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v), g_pal):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-4,
+                                   rtol=1e-4)
+
+
+def _pallas_kernels(jaxpr) -> list:
+    """The ``name`` of each pallas_call in a jaxpr, sub-jaxprs included
+    (None for the unnamed forward kernel)."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _pallas_kernels(sub)
+    return names
+
+
+def test_pallas_primal_runs_forward_kernel_only():
+    """Serving prefill calls the primal alone: one forward kernel, no lse
+    pass; the gradient adds the lse, dK/dV and dQ kernels."""
+    q = jnp.zeros((1, 256, 4, 128), jnp.bfloat16)
+    kv = jnp.zeros((1, 256, 1, 128), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return ops.attention(q, k, v, impl="pallas", q_block=128,
+                             kv_block=128)
+
+    primal = _pallas_kernels(jax.make_jaxpr(fwd)(q, kv, kv).jaxpr)
+    assert primal == [None]
+    grad = _pallas_kernels(jax.make_jaxpr(jax.grad(
+        lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2)))(
+            q, kv, kv).jaxpr)
+    assert sorted(grad, key=str) == [None, "flash_bwd_dkv", "flash_bwd_dq",
+                                     "flash_lse"], grad
+
+
 # -- GQA wrapper ----------------------------------------------------------------
 
 
