@@ -170,6 +170,7 @@ def run_layers(params: Params, x: jax.Array, cfg: ArchConfig, ec: ExecConfig
     return L.norm(subtree(params, "final_norm"), h, cfg), aux
 
 
+@jax.named_scope("lm_head")   # the vocabulary-parallel head's exchange
 def chunked_xent(h: jax.Array, w_out: jax.Array, labels: jax.Array,
                  mask: Optional[jax.Array] = None, chunk: int = 512
                  ) -> jax.Array:

@@ -82,11 +82,12 @@ def _act(x: jax.Array, kind: str) -> jax.Array:
 def mlp(p: Params, x: jax.Array, cfg: ArchConfig) -> jax.Array:
     if cfg.mlp_gated:
         h = _act(x @ p["wg"], cfg.act) * (x @ p["wu"])
-        h = shard_act(h, ("dp", None, "tp"))
-        return h @ p["wd"]
-    h = _act(x @ p["wu"] + p["bu"], cfg.act)
+    else:
+        h = _act(x @ p["wu"] + p["bu"], cfg.act)
     h = shard_act(h, ("dp", None, "tp"))
-    return h @ p["wd"] + p["bd"]
+    with jax.named_scope("mlp_down"):
+        out = h @ p["wd"]
+    return out if cfg.mlp_gated else out + p["bd"]
 
 
 # ---------------------------------------------------------------------------
@@ -262,7 +263,8 @@ def attention(p: Params, x: jax.Array, cfg: ArchConfig, exec_cfg: ExecConfig,
                     acc_dtype=kd.dtype)
 
     ctx = ctx.reshape(b_, s, h, hd)
-    out = jnp.einsum("bshk,hkd->bsd", ctx, p["wo"])
+    with jax.named_scope("attn_out"):
+        out = jnp.einsum("bshk,hkd->bsd", ctx, p["wo"])
     return out, new_cache
 
 
